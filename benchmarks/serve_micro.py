@@ -3,7 +3,8 @@
 Three scenarios over a repeat-template workload:
 
   * cold_warm — per-template first-submission latency (planning + jit
-    compilation) vs. steady-state warm latency through the plan cache.
+    compilation, the persistent compile cache kept out so an earlier run
+    cannot fill it) vs. steady-state warm latency through the plan cache.
     The acceptance bar is warm >= 5x faster at the workload median;
     result sets are asserted identical to a fresh single-query engine.
   * batched_serial — a zipfian template mix streamed through the server
@@ -34,6 +35,7 @@ import numpy as np
 
 from repro.core import Dataset, Thresholds
 from repro.data import DATASETS, random_query
+from repro.launch.compile_cache import persistent_cache_off
 from repro.serve import QueryServer
 
 SMOKE = os.environ.get("REPRO_BENCH_SERVE_SMOKE", "") not in ("", "0")
@@ -66,9 +68,10 @@ def _cold_warm(ds, pool, oracle):
     srv = QueryServer(ds, batching=False, calibrate=False)
     cold, warm, identical = [], [], True
     for q, ref in zip(pool, oracle):
-        t0 = time.perf_counter()
-        r = srv.query(q)
-        cold.append(time.perf_counter() - t0)
+        with persistent_cache_off():
+            t0 = time.perf_counter()
+            r = srv.query(q)
+            cold.append(time.perf_counter() - t0)
         identical &= r.result_set() == ref
         best = float("inf")
         for _ in range(WARM_REPS):
@@ -136,69 +139,47 @@ def _batched_serial(ds, pool, oracle):
 
 
 # ---------------------------- calibration ------------------------------ #
-_CAL_WORKER = r"""
-import json, sys, time
-from repro.core import Dataset, Thresholds
-from repro.data import DATASETS, random_query
-from repro.serve import QueryServer
-
-mode, scale, n = sys.argv[1], float(sys.argv[2]), int(sys.argv[3])
-g = DATASETS["lubm"](scale=scale, seed=1)
-ds = Dataset.build(g, variant="rdf_h")
-stream = [random_query(g, size=4, seed=300 + i) for i in range(n)]
-# tau forced so the planner marks every template complex AND selective:
-# the check runs unconditionally until calibration raises tau_sel
-srv = QueryServer(ds, thresholds=Thresholds(tau_iter=1.0, tau_join=1.0,
-                                           tau_sel=0.01),
-                  batching=False, calibrate=(mode == "calibrated"),
-                  plan_cache_size=2 * n)
-# pre-warm BOTH kernel paths (check-on masks and check-off intervals)
-# on out-of-stream templates, so the timed comparison is not dominated
-# by which mode happens to compile which path: a frozen server only
-# ever compiles the mask path, a calibrated one compiles both
-warm_eng = ds.engine("rdf_h")
-for i in range(4):
-    wq = random_query(g, size=4, seed=900 + i)
-    for policy in ("always", "never"):
-        warm_eng.cfg.check_policy = policy
-        warm_eng.execute(wq)
-t0 = time.perf_counter()
-sets = [srv.query(q).result_set() for q in stream]
-wall = time.perf_counter() - t0
-oracle = ds.engine("rdf_h")
-identical = all(s == oracle.execute(q).result_set()
-                for q, s in zip(stream, sets))
-t = srv.telemetry()
-print(json.dumps({
-    "wall_s": wall, "qps": n / wall, "identical": identical,
-    "checks_run": t["stats_rollup"].get("used_check", 0),
-    "check_time_s": t["stats_rollup"].get("check_time", 0.0),
-    "calibration": t["calibration"],
-}))
-"""
+def _calibration_stream(ds, stream, mode, n):
+    # tau forced so the planner marks every template complex AND selective:
+    # the check runs unconditionally until calibration raises tau_sel
+    srv = QueryServer(ds, thresholds=Thresholds(tau_iter=1.0, tau_join=1.0,
+                                               tau_sel=0.01),
+                      batching=False, calibrate=(mode == "calibrated"),
+                      plan_cache_size=2 * n)
+    t0 = time.perf_counter()
+    sets = [srv.query(q).result_set() for q in stream]
+    wall = time.perf_counter() - t0
+    t = srv.telemetry()
+    return sets, {
+        "wall_s": wall, "qps": n / wall,
+        "checks_run": t["stats_rollup"].get("used_check", 0),
+        "check_time_s": t["stats_rollup"].get("check_time", 0.0),
+        "calibration": t["calibration"],
+    }
 
 
 def _calibration():
     # coherent relational-like dataset + small templates: the §4.3 case
-    # where the neighborhood check rarely pays its cost.  Each mode runs
-    # in its own subprocess — in-process A/B is meaningless here because
-    # whichever mode runs first pays the shared jit compilations.
-    import subprocess
-    import sys
+    # where the neighborhood check rarely pays its cost.  Both modes run
+    # in this one process (one process may hold the chip): an untimed
+    # pass of each mode over the stream first compiles every program
+    # either mode uses, so the timed passes — each in a fresh server with
+    # a cold plan cache — compare planning and execution, not which mode
+    # happened to pay the shared compilations.
     n = 16 if SMOKE else 40
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    g = DATASETS["lubm"](scale=SCALE, seed=1)
+    ds = Dataset.build(g, variant="rdf_h")
+    stream = [random_query(g, size=4, seed=300 + i) for i in range(n)]
+    modes = ("default", "calibrated")
+    for mode in modes:
+        _calibration_stream(ds, stream, mode, n)
+    oracle = ds.engine("rdf_h")
+    want = [oracle.execute(q).result_set() for q in stream]
     out = {}
     identical = True
-    for mode in ("default", "calibrated"):
-        proc = subprocess.run(
-            [sys.executable, "-c", _CAL_WORKER, mode, str(SCALE), str(n)],
-            capture_output=True, text=True, env=env, timeout=1200)
-        if proc.returncode != 0:
-            raise RuntimeError(f"calibration worker {mode} failed:\n"
-                               f"{proc.stderr[-2000:]}")
-        res = json.loads(proc.stdout.strip().splitlines()[-1])
-        identical &= res.pop("identical")
-        out[mode] = res
+    for mode in modes:
+        sets, out[mode] = _calibration_stream(ds, stream, mode, n)
+        identical &= sets == want
     out["identical_result_sets"] = identical
     out["n_stream"] = n
     out["speedup"] = out["calibrated"]["qps"] / out["default"]["qps"]

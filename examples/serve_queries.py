@@ -5,13 +5,13 @@ replays a zipfian mix of them (the serving assumption: the same templates
 arrive over and over).  Prints per-phase latency, plan-cache hit rate,
 batch dedup, and the calibration state the server learned online.
 
-    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/serve_queries.py \\
+    PYTHONPATH=src python examples/serve_queries.py \\
         --dataset dblp --scale 0.05 --templates 6 --queries 60
 
 Governed serving (deadlines + admission control + degradation ladder +
 circuit breaker) with optional injected chaos:
 
-    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/serve_queries.py \\
+    PYTHONPATH=src python examples/serve_queries.py \\
         --governed --deadline-ms 250 --max-pending 6 --chaos
 
 Warm-restart durability: ``--snapshot PATH`` saves the server's learned
@@ -19,7 +19,7 @@ state (plans, calibration, governor memory) after the stream, then
 "restarts" into a fresh server via ``restore_snapshot`` and replays one
 query per template — every one should hit the plan cache warm:
 
-    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/serve_queries.py \\
+    PYTHONPATH=src python examples/serve_queries.py \\
         --governed --snapshot /tmp/serve.snap
 
 Observability: ``--trace PATH`` records every query (one trace id from
@@ -29,7 +29,7 @@ exports a Chrome trace viewable in chrome://tracing or ui.perfetto.dev;
 decision with its τ terms, the Selinger join order, and the learned
 join sequence with estimated-vs-observed rows:
 
-    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/serve_queries.py \\
+    PYTHONPATH=src python examples/serve_queries.py \\
         --governed --chaos --trace /tmp/serve_trace.json --explain
 """
 import argparse
@@ -39,6 +39,7 @@ import numpy as np
 
 from repro.core import Dataset
 from repro.data import DATASETS, random_query
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import GovernorConfig, QueryServer, ServingError
 
 
@@ -87,6 +88,7 @@ def main():
                          "template after the stream")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     governed = (args.governed or args.chaos or args.deadline_ms is not None
                 or args.max_pending is not None)
 

@@ -17,10 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
 
-try:                                    # jax >= 0.4.35 public location
-    from jax import shard_map
-except ImportError:                     # older releases
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..kernels import ref as kref
 
@@ -85,12 +82,8 @@ def gather_candidates(mesh: Mesh, mask: np.ndarray, cap: int) -> np.ndarray:
         ids = jnp.where(ids >= 0, ids + base, -1)
         return jax.lax.all_gather(ids, "data").reshape(-1)
 
-    try:        # jax >= 0.6 renamed check_rep -> check_vma
-        fn = shard_map(local, mesh=mesh, in_specs=(PS("data"),),
-                       out_specs=PS(), check_vma=False)
-    except TypeError:
-        fn = shard_map(local, mesh=mesh, in_specs=(PS("data"),),
-                       out_specs=PS(), check_rep=False)
+    fn = shard_map(local, mesh=mesh, in_specs=(PS("data"),),
+                   out_specs=PS(), check_vma=False)
     with mesh:
         dev = jax.device_put(mask_p, NamedSharding(mesh, PS("data")))
         out = np.asarray(fn(dev))

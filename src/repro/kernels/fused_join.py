@@ -146,7 +146,8 @@ def _probe(a_keys_s, b_keys_s, probe: str):
 
 # ----------------- segment-offset expand (Pallas seg) ------------------ #
 SEG_TILE_R = 8              # sublane rows per output tile -> 8*128 slots
-SEG_BLOCK = 128             # csum entries per block (one lane row)
+SEG_ROWS = 8                # lane rows per csum block: one (8, 128) tile
+SEG_BLOCK = SEG_ROWS * 128  # csum entries per block
 
 
 def _seg_kernel(csum_ref, seg_ref):
@@ -155,20 +156,20 @@ def _seg_kernel(csum_ref, seg_ref):
     Same block-skipping accumulation as merge_probe: csum is
     nondecreasing, so a csum block entirely <= the tile's smallest t
     contributes its full width, a block entirely > the largest t
-    contributes nothing, and only boundary blocks run the lane loop."""
+    contributes nothing, and a boundary block repeats the test per
+    128-entry row so only overlapping rows run the lane loop."""
     @pl.when(pl.program_id(1) == 0)
     def _init():
         seg_ref[...] = jnp.zeros_like(seg_ref)
 
     t0 = pl.program_id(0) * (SEG_TILE_R * 128)
+    t_max = t0 + SEG_TILE_R * 128 - 1
     r = jax.lax.broadcasted_iota(jnp.int32, seg_ref.shape, 0)
     l = jax.lax.broadcasted_iota(jnp.int32, seg_ref.shape, 1)
     t = t0 + r * 128 + l
-    c = csum_ref[...]                           # [1, SEG_BLOCK]
-    c_lo = c[0, 0]
-    c_hi = c[0, SEG_BLOCK - 1]
-    below = c_hi <= t0                          # block counts for every t
-    above = c_lo > t0 + SEG_TILE_R * 128 - 1
+    c = csum_ref[...]                           # [SEG_ROWS, 128]
+    below = c[SEG_ROWS - 1, 127] <= t0          # block counts for every t
+    above = c[0, 0] > t_max
 
     @pl.when(below)
     def _all_below():
@@ -176,10 +177,24 @@ def _seg_kernel(csum_ref, seg_ref):
 
     @pl.when(jnp.logical_not(below | above))
     def _overlap():
-        acc = jnp.zeros(seg_ref.shape, jnp.int32)
-        for j in range(SEG_BLOCK):
-            acc += (c[0, j] <= t).astype(jnp.int32)
-        seg_ref[...] += acc
+        def row(i, carry):
+            cr = csum_ref[pl.ds(i, 1), :]       # [1, 128]
+            r_below = cr[0, 127] <= t0
+            r_above = cr[0, 0] > t_max
+
+            @pl.when(r_below)
+            def _row_below():
+                seg_ref[...] += jnp.full(seg_ref.shape, 128, jnp.int32)
+
+            @pl.when(jnp.logical_not(r_below | r_above))
+            def _row_overlap():
+                acc = jnp.zeros(seg_ref.shape, jnp.int32)
+                for j in range(128):
+                    acc += (cr[0, j] <= t).astype(jnp.int32)
+                seg_ref[...] += acc
+            return carry
+
+        jax.lax.fori_loop(0, SEG_ROWS, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("cap", "interpret"))
@@ -193,12 +208,12 @@ def expand_segments_pallas(csum, cap: int, interpret: bool = False):
     # padding with INT32_MAX never counts: csum values are < 2^31 totals
     c_p = jnp.full((n_pad,), _I32_MAX, jnp.int32).at[:n].set(
         csum.astype(jnp.int32))
-    c_m = c_p.reshape(n_pad // SEG_BLOCK, SEG_BLOCK)
+    c_m = c_p.reshape(n_pad // 128, 128)
     grid = (cap_pad // span, n_pad // SEG_BLOCK)
     seg = pl.pallas_call(
         _seg_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((1, SEG_BLOCK), lambda i, k: (k, 0))],
+        in_specs=[pl.BlockSpec((SEG_ROWS, 128), lambda i, k: (k, 0))],
         out_specs=pl.BlockSpec((SEG_TILE_R, 128), lambda i, k: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((cap_pad // 128, 128), jnp.int32),
         interpret=interpret,

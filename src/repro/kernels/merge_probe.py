@@ -10,14 +10,15 @@ produce for every a-key the half-open range of equal b-keys:
 The expand/gather step of the join consumes (start, cnt) directly.
 
 TPU mapping: a is reshaped to (rows, 128) lanes and tiled over grid dim 0;
-b is walked in 128-wide blocks over grid dim 1, accumulating lt/eq counts
-into the revisited output block (the standard accumulation pattern).
-Because both sides are sorted, each b block first compares its min/max
-against the a tile's range: blocks entirely below contribute a uniform
-+TILE_B to `start`, blocks entirely above contribute nothing, and only the
-O(#a_tiles + #b_blocks) boundary-overlapping pairs run the lane-unrolled
-compare loop — the merge property that makes this near-linear despite the
-tiled formulation.
+b is walked in (8, 128) blocks (one int32 vreg tile, 1024 keys) over grid
+dim 1, accumulating lt/eq counts into the revisited output block (the
+standard accumulation pattern).  Because both sides are sorted, each b
+block first compares its min/max against the a tile's range: blocks
+entirely below contribute a uniform +TILE_B to `start`, blocks entirely
+above contribute nothing.  A boundary-overlapping block repeats the same
+test per 128-key row, and only rows that overlap the a tile run the
+lane-unrolled compare loop — the merge property that makes this
+near-linear despite the tiled formulation.
 """
 from __future__ import annotations
 
@@ -28,7 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 DEFAULT_TILE_R = 8          # sublane rows per a tile -> 8*128 keys
-TILE_B = 128                # b keys per block (one lane row)
+B_ROWS = 8                  # lane rows per b block: one (8, 128) int32 tile
+TILE_B = B_ROWS * 128       # b keys per block
 
 _I32_MAX = jnp.iinfo(jnp.int32).max
 
@@ -40,14 +42,11 @@ def _kernel(a_ref, b_ref, start_ref, cnt_ref):
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
     a = a_ref[...]                              # [TR, 128] sorted overall
-    b = b_ref[...]                              # [1, TILE_B] sorted
     a_min = jnp.min(a)
     a_max = jnp.max(a)
-    b_lo = b[0, 0]
-    b_hi = b[0, TILE_B - 1]
-
-    below = b_hi < a_min                        # whole block < every a key
-    above = b_lo > a_max                        # whole block > every a key
+    b = b_ref[...]                              # [B_ROWS, 128] sorted
+    below = b[B_ROWS - 1, 127] < a_min          # whole block < every a key
+    above = b[0, 0] > a_max                     # whole block > every a key
 
     @pl.when(below)
     def _all_below():
@@ -55,14 +54,28 @@ def _kernel(a_ref, b_ref, start_ref, cnt_ref):
 
     @pl.when(jnp.logical_not(below | above))
     def _overlap():
-        lt = jnp.zeros(a.shape, jnp.int32)
-        eq = jnp.zeros(a.shape, jnp.int32)
-        for j in range(TILE_B):
-            bj = b[0, j]
-            lt += (bj < a).astype(jnp.int32)
-            eq += (bj == a).astype(jnp.int32)
-        start_ref[...] += lt
-        cnt_ref[...] += eq
+        def row(r, carry):
+            br = b_ref[pl.ds(r, 1), :]          # [1, 128] sorted
+            r_below = br[0, 127] < a_min
+            r_above = br[0, 0] > a_max
+
+            @pl.when(r_below)
+            def _row_below():
+                start_ref[...] += jnp.full(start_ref.shape, 128, jnp.int32)
+
+            @pl.when(jnp.logical_not(r_below | r_above))
+            def _row_overlap():
+                lt = jnp.zeros(a.shape, jnp.int32)
+                eq = jnp.zeros(a.shape, jnp.int32)
+                for j in range(128):
+                    bj = br[0, j]
+                    lt += (bj < a).astype(jnp.int32)
+                    eq += (bj == a).astype(jnp.int32)
+                start_ref[...] += lt
+                cnt_ref[...] += eq
+            return carry
+
+        jax.lax.fori_loop(0, B_ROWS, row, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_r", "interpret"))
@@ -86,7 +99,7 @@ def merge_probe_pallas(a_keys: jax.Array, b_keys: jax.Array,
     a_p = jnp.full((a_pad,), _I32_MAX, jnp.int32).at[:n_a].set(a)
     b_p = jnp.full((b_pad,), _I32_MAX, jnp.int32).at[:n_b].set(b)
     a_m = a_p.reshape(a_pad // 128, 128)
-    b_m = b_p.reshape(b_pad // TILE_B, TILE_B)
+    b_m = b_p.reshape(b_pad // 128, 128)
 
     grid = (a_pad // span, b_pad // TILE_B)
     start, cnt = pl.pallas_call(
@@ -94,7 +107,7 @@ def merge_probe_pallas(a_keys: jax.Array, b_keys: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_r, 128), lambda i, k: (i, 0)),
-            pl.BlockSpec((1, TILE_B), lambda i, k: (k, 0)),
+            pl.BlockSpec((B_ROWS, 128), lambda i, k: (k, 0)),
         ],
         out_specs=[
             pl.BlockSpec((tile_r, 128), lambda i, k: (i, 0)),

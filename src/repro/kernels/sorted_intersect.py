@@ -4,9 +4,12 @@ The core of the paper's Algorithm 3 (Connectivity Check): for P candidate
 pairs, test whether the forward neighbor-id list of n_i intersects the
 backward neighbor-id list of n_j.  Lists are -1 padded.
 
-TPU mapping: grid over pair tiles; the B-side list is walked with an
-unrolled compare-any against the full A-side block — an O(A*B) VPU
-compare-reduce whose working set (TILE_P * (A + B) ints) is tiled to VMEM.
+TPU mapping: pairs are laid along lanes, 128 per grid step; both lists
+arrive transposed ([A, P] and [B, P]), so list position is the sublane
+axis.  A loop walks the B list one sublane row at a time and compares it
+against the whole A block (an O(A*B) VPU compare-reduce).  The loop is
+not unrolled, so the working set stays at the two blocks
+(128 * (A + B) ints) whatever the list widths.
 """
 from __future__ import annotations
 
@@ -16,43 +19,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_TILE_P = 256
+TILE_P = 128                # pairs per tile, one lane row
 
 
 def _kernel(a_ref, b_ref, out_ref):
-    a = a_ref[...]                                  # [TP, A]
-    b = b_ref[...]                                  # [TP, B]
-    hit = jnp.zeros((a.shape[0], 1), jnp.bool_)
-    for j in range(b.shape[1]):
-        bj = b[:, j:j + 1]                          # [TP, 1]
-        m = jnp.any((a == bj) & (bj >= 0), axis=1, keepdims=True)
-        hit = hit | m
-    out_ref[...] = jnp.broadcast_to(hit.astype(jnp.int32), out_ref.shape)
+    a = a_ref[...]                                  # [A, TP]
+
+    def col(j, hit):
+        bj = b_ref[pl.ds(j, 1), :]                  # [1, TP]
+        m = jnp.where((a == bj) & (bj >= 0), 1, 0)
+        return jnp.maximum(hit, jnp.max(m, axis=0, keepdims=True))
+
+    hit = jax.lax.fori_loop(0, b_ref.shape[0], col,
+                            jnp.zeros((1, a.shape[1]), jnp.int32))
+    out_ref[...] = jnp.broadcast_to(hit, out_ref.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("tile_p", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def intersect_any_pallas(a: jax.Array, b: jax.Array,
-                         *, tile_p: int = DEFAULT_TILE_P,
-                         interpret: bool = False) -> jax.Array:
+                         *, interpret: bool = False) -> jax.Array:
     """a [P, A] int32, b [P, B] int32 (-1 padded) -> hit [P] int32."""
     p, a_w = a.shape
     _, b_w = b.shape
-    tile_p = min(tile_p, max(8, -(-p // 8) * 8))
-    p_pad = -(-p // tile_p) * tile_p
-    a_pad = max(128, -(-a_w // 128) * 128)
+    p_pad = -(-max(p, 1) // TILE_P) * TILE_P
+    a_rows = max(8, -(-a_w // 8) * 8)
+    b_rows = max(8, -(-b_w // 8) * 8)
 
-    a_p = jnp.full((p_pad, a_pad), -1, jnp.int32).at[:p, :a_w].set(a)
-    b_p = jnp.full((p_pad, b_w), -1, jnp.int32).at[:p, :b_w].set(b)
+    a_t = jnp.full((a_rows, p_pad), -1, jnp.int32).at[:a_w, :p].set(a.T)
+    b_t = jnp.full((b_rows, p_pad), -1, jnp.int32).at[:b_w, :p].set(b.T)
 
     out = pl.pallas_call(
         _kernel,
-        grid=(p_pad // tile_p,),
+        grid=(p_pad // TILE_P,),
         in_specs=[
-            pl.BlockSpec((tile_p, a_pad), lambda i: (i, 0)),
-            pl.BlockSpec((tile_p, b_w), lambda i: (i, 0)),
+            pl.BlockSpec((a_rows, TILE_P), lambda i: (0, i)),
+            pl.BlockSpec((b_rows, TILE_P), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile_p, 128), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((p_pad, 128), jnp.int32),
+        out_specs=pl.BlockSpec((8, TILE_P), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((8, p_pad), jnp.int32),
         interpret=interpret,
-    )(a_p, b_p)
-    return out[:p, 0]
+    )(a_t, b_t)
+    return out[0, :p]

@@ -17,6 +17,7 @@ import numpy as np
 
 from ..core import Dataset, tune_thresholds, Thresholds
 from ..data import DATASETS, random_query
+from .compile_cache import enable_compile_cache
 
 
 def main():
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--tune", action="store_true",
                     help="grid-tune thresholds on a held-out sample first")
     args = ap.parse_args()
+    enable_compile_cache()
 
     g = DATASETS[args.dataset](scale=args.scale, seed=1)
     ds = Dataset.build(g, variant=args.variant)

@@ -35,13 +35,15 @@ def test_shard_check_matches_single_device():
     r = run_sub("""
     import json, numpy as np, jax
     from repro.core import build_ni_index
+    from jax.sharding import AxisType
     from repro.core.distributed import shard_check
     from repro.kernels import ref as kref
     from repro.data import random_graph
     g = random_graph(n_nodes=100, n_edges=300, seed=5)
     ni = build_ni_index(g, d_max=1)
     e = ni.entries[1]
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     lo = np.asarray([0, 40], np.int32); hi = np.asarray([30, 90], np.int32)
     need = np.asarray([1, 1], np.int32)
     got = shard_check(mesh, e.ids, lo, hi, need, e.overflow)
@@ -71,7 +73,7 @@ def test_sharded_train_step_matches_single():
     """DP+TP sharded train step == single-device step (same math)."""
     r = run_sub("""
     import json, numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as PS
     from repro.configs import ARCHS, reduced_config
     from repro.configs.base import InputShape, TrainConfig
     from repro.models import api
@@ -88,7 +90,8 @@ def test_sharded_train_step_matches_single():
     p1, o1, m1 = step1(params, opt, batch, 0)
 
     # 4x2 mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     pspec = api.model_pspecs(cfg, mesh)
     bspec = api.batch_pspecs(cfg, InputShape("s", 32, 4, "train"), mesh)
     ns = lambda t: jax.tree.map(lambda s: NamedSharding(mesh, s), t,
@@ -110,9 +113,10 @@ def test_sharded_train_step_matches_single():
 def test_elastic_shrink_and_reshard():
     r = run_sub("""
     import json, numpy as np, jax
-    from jax.sharding import PartitionSpec as PS
+    from jax.sharding import AxisType, PartitionSpec as PS
     from repro.runtime import shrink_mesh, reshard
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     small = shrink_mesh(mesh, "pod")
     x = np.arange(32, dtype=np.float32).reshape(8, 4)
     t = reshard({"x": x}, small, {"x": PS("data", "model")})

@@ -18,7 +18,7 @@ def test_elastic_restart_after_pod_loss(tmp_path):
             + textwrap.dedent(f"""
     import json
     import numpy as np, jax
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as PS
     from repro.configs import ARCHS, reduced_config
     from repro.configs.base import InputShape, TrainConfig
     from repro.models import api
@@ -41,7 +41,8 @@ def test_elastic_restart_after_pod_loss(tmp_path):
                             is_leaf=lambda x: isinstance(x, PS))
 
     # phase 1: multi-pod mesh (2,2,2)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(AxisType.Auto,) * 3)
     params = api.init_model(cfg, 0)
     opt = adamw_init(params)
     with mesh:

@@ -108,16 +108,17 @@ def test_sort_probe_expand_probe_impl_parity(probe):
     assert rows_multiset(got) == want
 
 
-def test_expand_segments_pallas_matches_searchsorted():
+@pytest.mark.parametrize("n,cap", [(17, 256), (200, 1024), (1, 64),
+                                   (1000, 8192), (5000, 40000)])
+def test_expand_segments_pallas_matches_searchsorted(n, cap):
     rng = np.random.default_rng(5)
-    for n, cap in ((17, 256), (200, 1024), (1, 64)):
-        cnt = rng.integers(0, 9, n).astype(np.int32)
-        csum = np.cumsum(cnt).astype(np.int32)
-        seg = np.asarray(kfused.expand_segments_pallas(
-            jnp.asarray(csum), cap, interpret=True))
-        t = np.arange(cap)
-        want = np.searchsorted(csum, t, side="right").astype(np.int32)
-        assert (seg == want).all(), (n, cap)
+    cnt = rng.integers(0, 9, n).astype(np.int32)
+    csum = np.cumsum(cnt).astype(np.int32)
+    seg = np.asarray(kfused.expand_segments_pallas(
+        jnp.asarray(csum), cap, interpret=True))
+    t = np.arange(cap)
+    want = np.searchsorted(csum, t, side="right").astype(np.int32)
+    assert (seg == want).all(), (n, cap)
 
 
 def test_fused_overflow_resume_skips_resort():

@@ -16,10 +16,11 @@ def test_analyzer_counts_loops_and_collectives():
     os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=8'
     import json
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as PS
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as PS
     from repro.launch.hlo_analysis import analyze
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     N_ITERS, B, D, F = 4, 8, 64, 128
 
     def f(w1, w2, x):

@@ -88,7 +88,8 @@ def _sorted_keys(n, hi=500, sentinel=None, frac_pad=0.2):
 
 
 @pytest.mark.parametrize("na,nb", [(1, 1), (7, 130), (128, 128),
-                                   (300, 77), (1000, 513), (257, 8)])
+                                   (300, 77), (1000, 513), (257, 8),
+                                   (1000, 5000), (5000, 1000)])
 def test_merge_probe_sweep(na, nb):
     a = _sorted_keys(na, sentinel=(1 << 31) - 1)       # a-side invalid pads
     b = _sorted_keys(nb, sentinel=(1 << 31) - 2)       # b-side invalid pads
