@@ -4,7 +4,9 @@ Drives the path a user calls, once, in this one process:
 
     lubm graph from --seed -> Dataset.build -> QueryServer (impl="auto",
     so every join kernel resolves to its compiled Pallas form) -> a pool
-    of random_query templates submitted cold, then again warm
+    of random_query templates submitted cold, then again warm, then cold
+    and warm on a fresh server under
+    jax.transfer_guard_device_to_host("disallow")
 
 and checks every answer against an Engine over the same Dataset with
 impl="ref" (the kernels' pure-jnp twins).  The governor is off, so no
@@ -97,6 +99,22 @@ def smoke(scale: float, seed: int, n_templates: int) -> None:
     _log(f"cold_s={cold_s}")
     warm_s, warm, warm_rows = _serve(server, pool)
     _log(f"warm_s={warm_s}")
+
+    # the served pass once more, cold then warm on a fresh server, with
+    # every implicit device-to-host read refused: on a TPU each read the
+    # serving path makes has to be repro.obs.trace.host_read's explicit
+    # one (CPU arrays are host memory, so there the guard never fires)
+    guarded = QueryServer(ds, calibrate=False)
+    with jax.transfer_guard_device_to_host("disallow"):
+        for phase in ("cold", "warm"):
+            guard_s, _, guard_rows = _serve(guarded, pool)
+            for i, (got, want) in enumerate(zip(guard_rows, cold_rows)):
+                if got.shape != want.shape or (got != want).any():
+                    raise RuntimeError(f"template {i} guarded {phase}: "
+                                       f"{len(got)} rows, cold pass "
+                                       f"{len(want)}")
+            _log(f"guarded_{phase}_s={guard_s}: no implicit "
+                 f"device-to-host read")
 
     t = server.telemetry()
     if t["query_errors"] or t["queries_shed"]:

@@ -44,7 +44,7 @@ from .graph import RDFGraph
 from .ni_index import NIIndex
 from .matching import (Table, DEFAULT_NESTED_MAX, join_tables, planned_join,
                        dedup_project, empty_table, filter_rows, _pow2)
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, host_read
 from ..kernels import ops
 
 
@@ -365,7 +365,8 @@ def connectivity_mask_vectorized(graph: RDFGraph, ni: NIIndex,
         a, b = a_nodes[s:e], b_nodes[s:e]
         fa, ofa = reach_sets(ni, a, h_fwd, +1)
         bb, ofb = reach_sets(ni, b, h_bwd, -1)
-        hit = np.asarray(ops.intersect_any(fa, bb, impl=impl), dtype=bool)
+        hit = np.asarray(host_read(ops.intersect_any(fa, bb, impl=impl),
+                                   "intersect"), dtype=bool)
         of = ofa | ofb
         for i in np.nonzero(of)[0]:
             fs = _bfs_within(graph, a[i], h_fwd, True)
@@ -455,7 +456,8 @@ def distinct_column_values(table: Table, col: int) -> np.ndarray:
     these drive the host-side NI gathers)."""
     if table.count == 0:
         return np.empty(0, np.int32)
-    vals = np.asarray(table.rows[: table.count, table.cols.index(col)])
+    vals = host_read(table.rows[: table.count, table.cols.index(col)],
+                     "endpoints")
     u = np.unique(vals)
     return u[u >= 0].astype(np.int32)
 

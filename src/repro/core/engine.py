@@ -59,7 +59,7 @@ from .planner import (Thresholds, CostModel, PlanDecision, decide,
                       plan_table_joins, plan_connections, ConnFeatures,
                       choose_connection_impl)
 from .stats import DatasetStats, connection_selectivity, endpoint_reach
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, host_read, host_syncs
 
 
 @dataclass
@@ -96,10 +96,14 @@ class QueryStats:
     candidates_before: int = 0
     candidates_after: int = 0
     prepare_time: float = 0.0           # template planning (0 on cache hits)
+    # host perf_counter deltas: on a TPU they time the enqueue of each
+    # phase, not its device work, which lands at the next host read; the
+    # tracer's spans on the profiler's clock are the measure there
     check_time: float = 0.0
     match_time: float = 0.0
     conn_time: float = 0.0
     total_time: float = 0.0
+    host_syncs: int = 0                 # device->host reads (obs.host_read)
     cache_hit: bool = False             # executed from a warm PreparedQuery
     result_cache_hit: bool = False      # served from the ResultCache
     join_work: int = 0                  # Σ |A|*|B| over joins (work proxy)
@@ -150,7 +154,7 @@ class QueryStats:
         "conn_reach_pairs", "conn_connected_pairs",
         "conn_endpoint_rows", "conn_endpoint_distinct",
         "conn_est_pairs", "conn_est_reach_pairs",
-        "budget_checks",
+        "budget_checks", "host_syncs",
     )
 
     def to_dict(self) -> dict:
@@ -489,6 +493,7 @@ class Engine:
         it was handed) when a bound is blown.  The core never imports the
         serving layer — any object with that method works."""
         t0 = time.perf_counter()
+        syncs0 = host_syncs()
         qs = QueryStats()
         cfg = self.cfg
         query, iv, cand_sizes = pq.query, pq.iv, pq.cand_sizes
@@ -639,7 +644,8 @@ class Engine:
 
         pq.executions += 1
         qs.total_time = time.perf_counter() - t0
-        rows = np.asarray(final.rows[: final.count])
+        rows = host_read(final.rows[: final.count], "rows")
+        qs.host_syncs = host_syncs() - syncs0
         return MatchResult(cols=final.cols, rows=rows, stats=qs)
 
     # -------------------------------------------------------------- #
@@ -823,7 +829,7 @@ class Engine:
                         telemetry=tel, record=record_join, info=info,
                         fuse=self.cfg.fuse_joins, tracer=tracer)
                 else:
-                    rows = np.asarray(tab.rows[: tab.count])
+                    rows = host_read(tab.rows[: tab.count], "rows")
                     a = rows[:, tab.cols.index(c.src)]
                     b = rows[:, tab.cols.index(c.dst)]
                     keep = connectivity_mask(self.graph, self.ni, a, b,
@@ -880,7 +886,8 @@ class Engine:
                     # materialized rows to the budget here
                     ck(rows=joined.count, cap=joined.cap)
                     if joined.count:
-                        rows = np.asarray(joined.rows[: joined.count])
+                        rows = host_read(joined.rows[: joined.count],
+                                         "rows")
                         a = rows[:, joined.cols.index(c.src)]
                         b = rows[:, joined.cols.index(c.dst)]
                         keep = connectivity_mask(self.graph, self.ni,

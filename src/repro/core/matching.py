@@ -49,7 +49,7 @@ import jax.numpy as jnp
 
 from .graph import RDFGraph
 from .decompose import DTree
-from ..obs.trace import NULL_TRACER
+from ..obs.trace import NULL_TRACER, host_read
 from ..kernels import ops as kops
 from ..kernels import fused_join as kfused
 from ..kernels import radix_join as krad
@@ -116,7 +116,7 @@ class CandidateTable:
         return int(self.rows.shape[0])
 
     def numpy(self) -> np.ndarray:
-        return np.asarray(self.rows[: self.count])
+        return host_read(self.rows[: self.count], "rows")
 
     def result_set(self) -> set[tuple[int, ...]]:
         """Deduplicated rows in *canonical* column order (columns sorted
@@ -220,10 +220,21 @@ def _join_gather(eq, a_rows, b_rows, new_sel, size, has_new):
 
 def edge_pairs(graph: RDFGraph, pred_id: int | None,
                pass_src, pass_dst,
-               cols: tuple[int, int], cap: int | None = None) -> Table:
+               cols: tuple[int, int], cap: int | None = None,
+               tracer=None) -> Table:
     """All edges (s, d) with pred==pred_id (None = any) and both endpoint
     specs satisfied.  A spec is a full-[N] bool mask or a (lo, hi)
     interval pair (wildcard candidates).  Returns a 2-column table."""
+    if tracer is None:
+        tracer = NULL_TRACER
+    with tracer.span("edge_pairs", pred=pred_id) as sp:
+        out = _edge_pairs(graph, pred_id, pass_src, pass_dst, cols, cap)
+        if sp.live:
+            sp.set(rows=out.count, cap=out.cap)
+    return out
+
+
+def _edge_pairs(graph, pred_id, pass_src, pass_dst, cols, cap) -> Table:
     src = jnp.asarray(graph.src)
     dst = jnp.asarray(graph.dst)
     pred = jnp.asarray(graph.pred)
@@ -233,7 +244,7 @@ def edge_pairs(graph: RDFGraph, pred_id: int | None,
                             dst_iv=isinstance(pass_dst, tuple))
     if cols[0] == cols[1]:      # query self-loop: s == d, single column
         mask = mask & (src == dst)
-        count = int(mask.sum())
+        count = int(host_read(mask.sum(), "edge_count"))
         cap2 = cap or _pow2(count)
         if count > cap2:
             raise CapacityOverflow(count)
@@ -242,7 +253,7 @@ def edge_pairs(graph: RDFGraph, pred_id: int | None,
                       src[jnp.minimum(idx, graph.num_edges - 1)], -1)
         return Table(cols=(cols[0],), rows=s[:, None].astype(jnp.int32),
                      count=count)
-    count = int(mask.sum())
+    count = int(host_read(mask.sum(), "edge_count"))
     if cap is None:
         cap = _pow2(count)
     if count > cap:
@@ -473,7 +484,7 @@ def _join_sorted(a: Table, b: Table, shared, new, cap, row_limit,
         # a skewed >2^31-match join would hit on device.  The same array
         # serves the capacity check, the overflow clip below, and — via
         # _ProbeResume on CapacityOverflow — the exact-size retry.
-        cnt_np = np.asarray(cnt)
+        cnt_np = host_read(cnt, "join_counts")
     else:
         a_rows_s, b_rows_s = resume.a_rows_s, resume.b_rows_s
         start, cnt, cnt_np = resume.start, resume.cnt, resume.cnt_np
@@ -533,7 +544,7 @@ def _join_sorted_fused(a: Table, b: Table, a_sel, b_sel, key_cols,
         telemetry.sorts_performed += 2
     a.cache_run(key_cols, a_rows_s, a_keys_s, "a")
     b.cache_run(key_cols, b_rows_s, b_keys_s, "b")
-    total = int(total_dev)          # the ONE host sync of this join
+    total = int(host_read(total_dev, "join_total"))   # the ONE host sync
     out_count = total if row_limit is None else min(total, row_limit)
     truncated = row_limit is not None and total > row_limit
     if cap is None:
@@ -544,7 +555,7 @@ def _join_sorted_fused(a: Table, b: Table, a_sel, b_sel, key_cols,
     elif out_count > cap:
         err = CapacityOverflow(out_count)
         err.resume = _ProbeResume(a_rows_s, b_rows_s, start, cnt,
-                                  np.asarray(cnt), key_cols)
+                                  host_read(cnt, "join_counts"), key_cols)
         raise err
     return Table(cols=out_cols, rows=rows, count=out_count,
                  truncated=truncated, sort_order=key_cols)
@@ -591,7 +602,8 @@ def _join_radix(a: Table, b: Table, shared, new, cap, row_limit,
         bits = _radix_bits(b.count)
         b_keys_p, b_rows_p, edges, maxlen = krad.radix_partition(
             b_keys, b.rows, bits)
-        lmax = _pow2(int(maxlen), lo=8)     # one scalar sync (window size)
+        # one scalar sync (window size)
+        lmax = _pow2(int(host_read(maxlen, "radix_window")), lo=8)
         if a.cap * lmax > RADIX_WORK_MAX:
             # skew: the widest bucket would make the window matrix
             # quadratic — sort-merge is strictly better here
@@ -600,7 +612,8 @@ def _join_radix(a: Table, b: Table, shared, new, cap, row_limit,
         win_keys, win_start = krad.radix_window(a_keys, edges, b_keys_p,
                                                 bits, lmax)
         lt, cnt = kops.radix_probe(a_keys, win_keys, impl=probe_impl)
-        total = int(jnp.sum(cnt))           # second scalar sync (total)
+        # second scalar sync (total)
+        total = int(host_read(jnp.sum(cnt), "join_total"))
     else:
         b_rows_p, lt, cnt = resume.b_rows_p, resume.lt, resume.cnt
         win_start = resume.win_start
@@ -662,7 +675,7 @@ def _join_nested(a: Table, b: Table, shared, new, cap, chunk, b_chunk,
         for start in range(0, max(a.count, 1), chunk):
             a_rows = a.rows[start:start + chunk]
             eq = _join_chunk_mask(a_rows, b_rows_t, a_sel, b_sel)
-            cnt = int(eq.sum())
+            cnt = int(host_read(eq.sum(), "join_total"))
             if cnt == 0:
                 continue
             if row_limit is not None:
@@ -895,11 +908,11 @@ def dtree_candidates(graph: RDFGraph, tree: DTree,
             if outgoing:
                 pairs = edge_pairs(graph, pred, pass_masks[tree.root],
                                    pass_masks[child],
-                                   cols=(tree.root, child))
+                                   cols=(tree.root, child), tracer=tracer)
             else:
                 pairs = edge_pairs(graph, pred, pass_masks[child],
                                    pass_masks[tree.root],
-                                   cols=(child, tree.root))
+                                   cols=(child, tree.root), tracer=tracer)
             if table is None:
                 table = pairs
             else:
@@ -942,7 +955,7 @@ def injective_filter(table: Table) -> Table:
         return table
     # full-capacity mask (pow2 shape, no per-count recompiles)
     keep = _injective_keep(table.rows, pairs)
-    kept = int(keep.sum())
+    kept = int(host_read(keep.sum(), "filter_count"))
     if kept == table.count:
         return table
     return filter_rows(table, keep, kept=kept)
@@ -978,7 +991,7 @@ def dedup_project(table: Table, cols: tuple[int, ...],
     cols = tuple(cols)
     sel = tuple(table.cols.index(c) for c in cols)
     proj, keep, kept_dev = kfused.lexsort_distinct(table.rows, sel)
-    kept = int(kept_dev)
+    kept = int(host_read(kept_dev, "distinct_count"))
     rows = _filter_gather(proj, keep, _pow2(kept))
     return Table(cols=cols, rows=rows, count=kept, truncated=table.truncated,
                  sort_order=cols)
@@ -1001,7 +1014,7 @@ def filter_rows(table: Table, keep, kept: int | None = None) -> Table:
         keep = k
     keep = jnp.asarray(keep, dtype=bool)
     if kept is None:
-        kept = int(keep.sum())
+        kept = int(host_read(keep.sum(), "filter_count"))
     cap = _pow2(kept)
     rows = _filter_gather(table.rows, keep, cap)
     # compaction is order-preserving: the surviving rows keep their

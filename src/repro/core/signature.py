@@ -23,6 +23,7 @@ from .graph import RDFGraph
 from .ni_index import NIIndex
 from .query import QueryTemplate
 from ..kernels import ops
+from ..obs.trace import host_read
 
 
 @dataclass
@@ -179,8 +180,9 @@ def check_interval_candidates(ni: NIIndex, reqs: NodeReqs,
             max_d = int(np.max(np.nonzero(dreq.need.any(axis=1))[0]) + 1)
             for d in range(1, min(d_check, max_d) + 1):
                 entry = ni.entries[sign * d]
-                cnt = np.asarray(_gather_count(
-                    dev_ids(sign, d), cands_dev, lo_dev, hi_dev))
+                cnt = host_read(_gather_count(
+                    dev_ids(sign, d), cands_dev, lo_dev, hi_dev),
+                    "check_counts")
                 cum += cnt[: stop - start, :j]
                 over |= entry.overflow[cands[: stop - start]]
                 if dreq.need[d - 1].sum() > 0:
@@ -253,6 +255,7 @@ def bloom_prefilter(sigs: np.ndarray, entry, reqs: NodeReqs,
         return np.ones(n_cand, dtype=bool)
     required = np.asarray([e[0] for e in exact], np.int64)
     qsig = bloom_query_sig(required)
-    ok = np.asarray(ops.bitmask_contains(sigs[lo:hi], qsig, impl=impl),
+    ok = np.asarray(host_read(ops.bitmask_contains(sigs[lo:hi], qsig,
+                                                   impl=impl), "bloom"),
                     dtype=bool)
     return ok | entry.overflow[lo:hi]

@@ -23,7 +23,21 @@ span timing.  ``export_chrome(path)`` writes the Chrome trace event
 format (one ``ph: "X"`` complete event per span, pid 1, one tid per
 trace) loadable in chrome://tracing or Perfetto.
 
-Stdlib-only: imported by ``repro.core`` without creating an import cycle.
+The profiler's clock: a live span also opens a
+``jax.profiler.TraceAnnotation`` named ``rdfh.<span name>`` for its
+lifetime, with its scalar attributes (and the trace id) as metadata, so
+under ``jax.profiler`` every span lands on the host plane of the
+``.xplane.pb`` beside the device's programs.  Outside a profile the
+annotation costs a couple of microseconds.
+
+Host syncs: `host_read(x, what)` is the one way the serving path copies a
+device value to the host.  It counts every call in a process counter
+(`host_syncs()`, which `QueryStats.host_syncs` differences per
+execution) and, while a live tracer has a segment open, wraps the copy
+in a ``sync`` span: the host waiting on the device, plus the copy.
+
+Stdlib-only at import: ``repro.core`` imports this module, so JAX is
+imported lazily, inside the live path and inside `host_read`.
 """
 from __future__ import annotations
 
@@ -31,6 +45,50 @@ import itertools
 import json
 import time
 from collections import deque
+
+_profiler = None                # jax.profiler, bound by the first live span
+_live: "Tracer | None" = None   # the tracer with an open segment, if any
+_host_syncs = 0                 # host_read calls in this process
+
+
+def _scalars(attrs: dict) -> dict:
+    """The attributes a profiler annotation can carry as metadata."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
+def _open_annotation(name: str, attrs: dict):
+    """An entered ``jax.profiler.TraceAnnotation`` for a live span."""
+    global _profiler
+    if _profiler is None:
+        import jax.profiler
+        _profiler = jax.profiler
+    ann = _profiler.TraceAnnotation(f"rdfh.{name}", **_scalars(attrs))
+    ann.__enter__()
+    return ann
+
+
+def host_read(x, what: str):
+    """`jax.device_get(x)`, counted, and timed as a ``sync`` span (attr
+    ``what``) under the live tracer's open segment.  With tracing off it
+    adds one integer increment to the read."""
+    global _host_syncs
+    _host_syncs += 1
+    tracer = _live
+    if tracer is None:
+        return _device_get(x)
+    with tracer.span("sync", what=what):
+        return _device_get(x)
+
+
+def _device_get(x):
+    import jax
+    return jax.device_get(x)
+
+
+def host_syncs() -> int:
+    """`host_read` calls so far in this process."""
+    return _host_syncs
 
 
 class _NullSpan:
@@ -57,7 +115,7 @@ class Span:
     Use as a context manager; an exception propagating through stamps
     ``error`` with the exception type name and never swallows it."""
     __slots__ = ("name", "parent", "start", "end", "attrs", "error",
-                 "_trace", "_tracer")
+                 "_trace", "_tracer", "_annotation")
     live = True
 
     def __init__(self, tracer: "Tracer", name: str, trace: "Trace",
@@ -69,10 +127,15 @@ class Span:
         self.attrs = attrs
         self.error: str | None = None
         self.end: float | None = None
+        self._annotation = _open_annotation(
+            name, {"trace_id": trace.trace_id, **attrs})
         self.start = time.perf_counter()
 
     def set(self, **attrs) -> "Span":
         self.attrs.update(attrs)
+        meta = _scalars(attrs)
+        if meta:
+            self._annotation.set_metadata(**meta)
         return self
 
     @property
@@ -88,9 +151,11 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        global _live
         if exc_type is not None:
             self.error = exc_type.__name__
         self.end = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         stack = self._tracer._stack
         if stack and stack[-1] is self:
             stack.pop()
@@ -99,6 +164,8 @@ class Span:
                 stack.remove(self)
             except ValueError:
                 pass
+        if not stack and _live is self._tracer:
+            _live = None
         return False
 
 
@@ -174,12 +241,14 @@ class Tracer:
         return self._open(name, parent._trace, parent, attrs)
 
     def _open(self, name, trace, parent, attrs):
+        global _live
         if len(trace.spans) >= self.max_spans_per_trace:
             self.dropped_spans += 1
             return NULL_SPAN
         span = Span(self, name, trace, parent, attrs)
         trace.spans.append(span)
         self._stack.append(span)
+        _live = self
         return span
 
     def finish(self, trace_id: str | None) -> Trace | None:

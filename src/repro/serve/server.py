@@ -610,28 +610,30 @@ class QueryServer:
             self.metrics.counter("query_errors").inc()
             self.tracer.finish(f.trace_id)
             return
-        warm = bool(res.stats.cache_hit)
-        f._resolve(remap_result(res, order), latency)
-        self.queries_served += 1
-        m = self.metrics
-        m.counter("queries_served").inc()
-        m.histogram("latency_s").observe(latency)
-        m.histogram("latency_warm_s" if warm
-                    else "latency_cold_s").observe(latency)
-        m.histogram("result_rows").observe(res.count)
-        if self.slow_query_s is not None and latency >= self.slow_query_s:
-            m.counter("slow_queries").inc()
-            pq = (self.plan_cache.peek(self.dataset_id, f.fingerprint)
-                  if f.fingerprint is not None else None)
-            self._slow_log.append({
-                "fingerprint": f.fingerprint,
-                "trace_id": f.trace_id,
-                "latency_s": latency,
-                "warm": warm,
-                "explain": (None if pq is None else
-                            render_explain(pq,
-                                           self.engine.cfg.thresholds)),
-            })
+        with self.tracer.segment("finish", f.trace_id):
+            warm = bool(res.stats.cache_hit)
+            f._resolve(remap_result(res, order), latency)
+            self.queries_served += 1
+            m = self.metrics
+            m.counter("queries_served").inc()
+            m.histogram("latency_s").observe(latency)
+            m.histogram("latency_warm_s" if warm
+                        else "latency_cold_s").observe(latency)
+            m.histogram("result_rows").observe(res.count)
+            if self.slow_query_s is not None \
+                    and latency >= self.slow_query_s:
+                m.counter("slow_queries").inc()
+                pq = (self.plan_cache.peek(self.dataset_id, f.fingerprint)
+                      if f.fingerprint is not None else None)
+                self._slow_log.append({
+                    "fingerprint": f.fingerprint,
+                    "trace_id": f.trace_id,
+                    "latency_s": latency,
+                    "warm": warm,
+                    "explain": (None if pq is None else
+                                render_explain(pq,
+                                               self.engine.cfg.thresholds)),
+                })
         self.tracer.finish(f.trace_id)
 
     def _observe_stats(self, qs) -> None:

@@ -12,6 +12,7 @@ Three contracts under test:
     execute → governor routing → engine joins) all carry that query's
     trace id, and every ServingError names the trace that explains it.
 """
+import glob
 import json
 import time
 
@@ -242,6 +243,80 @@ def test_end_to_end_chaos_trace_export(graph, pool, tmp_path):
     all_names = {n for names in names_by_trace.values() for n in names}
     # governor + engine spans land inside the right query's trace
     assert {"breaker", "ladder", "rung", "join"} <= all_names
+
+
+# ----------------------- the profiler's clock -------------------------- #
+def _host_events(directory):
+    """(name, start ns, end ns) of every event on the host planes of the
+    one profile under `directory`."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(f"{directory}/**/*.xplane.pb", recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+
+
+def test_traced_server_spans_land_on_the_profiler_clock(graph, pool,
+                                                        tmp_path):
+    """Under jax.profiler every live span is an `rdfh.<name>` annotation
+    on the host plane, nested as the spans are."""
+    import jax
+    q = next(q for q in pool if q.connections)
+    srv = QueryServer(graph, cfg=_forcing_cfg(), tracer=Tracer())
+    srv.query(q)                         # compile outside the profile
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with jax.profiler.TraceAnnotation("bench.window"):
+            res = srv.query(q)
+    finally:
+        jax.profiler.stop_trace()
+    assert res.stats.join_strategies and res.stats.conn_strategies
+    events = _host_events(tmp_path)
+    (lo, hi), = [(s, e) for n, s, e in events if n == "bench.window"]
+    spans: dict = {}
+    for n, s, e in events:
+        if n.startswith("rdfh."):
+            spans.setdefault(n, []).append((s, e))
+    assert {"rdfh.submit", "rdfh.prepare", "rdfh.execute", "rdfh.finish",
+            "rdfh.edge_pairs", "rdfh.join", "rdfh.conn_edge",
+            "rdfh.sync"} <= set(spans)
+    assert all(lo <= s <= e <= hi for iv in spans.values() for s, e in iv)
+    for s, e in spans["rdfh.join"] + spans["rdfh.edge_pairs"]:
+        assert any(a <= s and e <= b for a, b in spans["rdfh.execute"])
+
+
+def test_host_syncs_alike_traced_or_not_and_one_sync_span_each(graph, pool):
+    """`QueryStats.host_syncs` does not depend on tracing, and a traced
+    execution holds one `sync` span per counted read."""
+    traced = QueryServer(graph, cfg=_forcing_cfg(), tracer=Tracer())
+    plain = QueryServer(graph, cfg=_forcing_cfg())
+    for q in pool * 2:                   # cold, then warm replay
+        f = traced.submit(q)
+        got = f.result().stats.host_syncs
+        assert got == plain.query(q).stats.host_syncs > 0
+        spans = traced.tracer.get(f.trace_id).spans
+        syncs = [s for s in spans if s.name == "sync"]
+        assert got == len(syncs)
+        assert all(s.attrs["what"] for s in syncs)
+
+
+def test_untraced_server_opens_no_profiler_annotation(graph, pool,
+                                                      monkeypatch):
+    import jax.profiler
+
+    def refuse(*args, **kwargs):
+        raise AssertionError("TraceAnnotation constructed")
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    srv = QueryServer(graph, cfg=_forcing_cfg())
+    for q in pool:
+        assert srv.query(q).stats.host_syncs > 0
+    with pytest.raises(AssertionError):  # the patch is the one spans use
+        QueryServer(graph, cfg=_forcing_cfg(),
+                    tracer=Tracer()).query(pool[0])
 
 
 def test_serving_errors_carry_trace_id_and_rung_history(graph, pool):

@@ -499,7 +499,7 @@ def test_query_stats_to_dict_schema_pinned():
         "conn_reach_pairs", "conn_connected_pairs",
         "conn_endpoint_rows", "conn_endpoint_distinct",
         "conn_est_pairs", "conn_est_reach_pairs",
-        "budget_checks", "degraded_steps",
+        "budget_checks", "host_syncs", "degraded_steps",
         "join_strategies", "conn_strategies", "plan",
     }
     d = QueryStats().to_dict()
