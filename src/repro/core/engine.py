@@ -120,6 +120,7 @@ class QueryStats:
     plan_mode: str = "cost"             # join order used (cost | greedy)
     sorts_performed: int = 0            # sort-merge sorts actually run
     sorts_avoided: int = 0              # skipped via sort-order/cached runs
+    edge_scan_rows: int = 0             # edge-array rows the D-tree scans read
     plan_cost: float = 0.0              # Σ est cost of executed join plans
     greedy_plan_cost: float = 0.0       # same cost model, greedy order
     # connection-edge telemetry (reach-join subsystem)
@@ -149,7 +150,7 @@ class QueryStats:
         "join_retries", "n_estimated_joins",
         "join_est_rows", "join_actual_rows",
         "join_est_log_err", "join_est_log_bias",
-        "plan_mode", "sorts_performed", "sorts_avoided",
+        "plan_mode", "sorts_performed", "sorts_avoided", "edge_scan_rows",
         "plan_cost", "greedy_plan_cost",
         "conn_reach_pairs", "conn_connected_pairs",
         "conn_endpoint_rows", "conn_endpoint_distinct",
@@ -641,6 +642,7 @@ class Engine:
         qs.conn_time = time.perf_counter() - t3
         qs.sorts_performed = tel.sorts_performed
         qs.sorts_avoided = tel.sorts_avoided
+        qs.edge_scan_rows = tel.edge_scan_rows
 
         pq.executions += 1
         qs.total_time = time.perf_counter() - t0

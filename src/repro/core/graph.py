@@ -8,8 +8,9 @@ strongest form: a prefix partial keyword resolves to a contiguous *node-id*
 interval, so candidate sets, NI entries and connectivity ID-lists all live in
 a single integer space.
 
-Host-side construction uses numpy; the heavy query phases consume the arrays
-directly (they are valid jnp inputs).
+Host-side construction uses numpy.  The edge scans read the edge arrays
+from `EdgeLayout`, a device-resident copy each graph builds once, whole and
+grouped by predicate.
 """
 from __future__ import annotations
 
@@ -17,6 +18,7 @@ from dataclasses import dataclass
 from functools import cached_property
 
 import numpy as np
+import jax.numpy as jnp
 
 RESOURCE = 0
 LITERAL = 1
@@ -25,6 +27,11 @@ REL = 0   # relationship predicate (resource -> resource)
 ATTR = 1  # attribute predicate  (resource -> literal)
 
 INVALID = np.int32(-1)
+
+
+def _pow2(x: int, lo: int = 64) -> int:
+    """The power of two at or above x (at least lo): a shape-stable size."""
+    return max(lo, 1 << (max(int(x), 1) - 1).bit_length())
 
 
 def _csr(num_nodes: int, key: np.ndarray, nbr: np.ndarray, pred: np.ndarray):
@@ -79,6 +86,58 @@ def csr_patch(csr, num_nodes: int, num_preds: int,
     return indptr2, nbr.astype(np.int32), pred.astype(np.int32)
 
 
+@dataclass(frozen=True)
+class EdgeLayout:
+    """The edge arrays on the device: whole, and grouped by predicate.
+
+    full:   (src, dst, pred) [E] int32, the graph's edge order.
+    groups: per predicate p, (src_p, dst_p, pred_p): p's edges in their
+            original relative order (a stable sort by predicate), padded to
+            `_pow2(count)` with rows pred = -1, src = dst = 0 (valid node
+            ids, so endpoint gathers stay in bounds).  None where p has no
+            edges, holds more than half of them, or pads to E rows or
+            more: a scan of p then reads the whole arrays.
+    counts: [P] edges per predicate.
+    """
+    full: tuple
+    groups: tuple
+    counts: np.ndarray
+
+    @staticmethod
+    def build(src: np.ndarray, dst: np.ndarray, pred: np.ndarray,
+              num_predicates: int) -> "EdgeLayout":
+        e = len(pred)
+        counts = np.bincount(pred, minlength=num_predicates)
+        order = np.argsort(pred, kind="stable")
+        starts = np.concatenate([[0], np.cumsum(counts)])
+        groups = []
+        for p, c in enumerate(counts):
+            cap = _pow2(c)
+            if c == 0 or 2 * c > e or cap >= e:
+                groups.append(None)
+                continue
+            idx = order[starts[p]:starts[p] + c]
+            padded = np.zeros((3, cap), np.int32)
+            padded[0, :c] = src[idx]
+            padded[1, :c] = dst[idx]
+            padded[2] = -1
+            padded[2, :c] = p
+            groups.append(tuple(jnp.asarray(a) for a in padded))
+        full = tuple(jnp.asarray(a) for a in (src, dst, pred))
+        return EdgeLayout(full=full, groups=tuple(groups), counts=counts)
+
+    def arrays(self, pred_id: int | None):
+        """The (src, dst, pred) arrays a scan for pred_id (None or < 0 =
+        any predicate) reads: the predicate's group where it has one, the
+        whole arrays otherwise, and None where the predicate has no edges."""
+        if pred_id is None or pred_id < 0:
+            return self.full
+        if pred_id >= len(self.counts) or self.counts[pred_id] == 0:
+            return None
+        group = self.groups[pred_id]
+        return self.full if group is None else group
+
+
 @dataclass
 class RDFGraph:
     """Immutable array-form RDF graph.
@@ -118,6 +177,11 @@ class RDFGraph:
     @cached_property
     def in_csr(self):
         return _csr(self.num_nodes, self.dst, self.src, self.pred)
+
+    @cached_property
+    def edge_layout(self) -> EdgeLayout:
+        return EdgeLayout.build(self.src, self.dst, self.pred,
+                                self.num_predicates)
 
     @cached_property
     def avg_degree(self) -> float:

@@ -1,8 +1,10 @@
 """D-tree candidate generation and joins (paper Algorithm 2, steps 2-3).
 
 TPU-native formulation: candidate generation is *edge-parallel* — one pass
-over the full edge array produces all (root, child) pairs matching a query
-edge (predicate + endpoint pass masks), with no per-node degree padding.
+over the edge arrays produces all (root, child) pairs matching a query edge
+(predicate + endpoint pass masks), with no per-node degree padding.  A
+named predicate's pass reads only that predicate's group of the graph's
+device-resident `EdgeLayout`.
 
 Joins are planned per-pair between three device-resident strategies:
 
@@ -47,7 +49,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from .graph import RDFGraph
+from .graph import RDFGraph, _pow2
 from .decompose import DTree
 from ..obs.trace import NULL_TRACER, host_read
 from ..kernels import ops as kops
@@ -169,14 +171,11 @@ Table = CandidateTable
 
 @dataclass
 class JoinTelemetry:
-    """Per-query sort-reuse counters (threaded from the engine down into
-    the sort-merge join path)."""
+    """Per-query counters, threaded from the engine down into the edge
+    scans and the sort-merge join path."""
     sorts_performed: int = 0
     sorts_avoided: int = 0
-
-
-def _pow2(x: int, lo: int = 64) -> int:
-    return max(lo, 1 << (max(int(x), 1) - 1).bit_length())
+    edge_scan_rows: int = 0     # edge-array rows the D-tree's scans read
 
 
 # ---------------------------------------------------------------------- #
@@ -221,23 +220,36 @@ def _join_gather(eq, a_rows, b_rows, new_sel, size, has_new):
 def edge_pairs(graph: RDFGraph, pred_id: int | None,
                pass_src, pass_dst,
                cols: tuple[int, int], cap: int | None = None,
-               tracer=None) -> Table:
+               tracer=None, telemetry: JoinTelemetry | None = None) -> Table:
     """All edges (s, d) with pred==pred_id (None = any) and both endpoint
     specs satisfied.  A spec is a full-[N] bool mask or a (lo, hi)
-    interval pair (wildcard candidates).  Returns a 2-column table."""
+    interval pair (wildcard candidates).  Returns a 2-column table.
+
+    The scan reads `graph.edge_layout.arrays(pred_id)`: the predicate's
+    group where it has one, else the whole edge arrays; a predicate with
+    no edges reads nothing.  telemetry counts the edge rows scanned."""
     if tracer is None:
         tracer = NULL_TRACER
     with tracer.span("edge_pairs", pred=pred_id) as sp:
-        out = _edge_pairs(graph, pred_id, pass_src, pass_dst, cols, cap)
+        arrays = graph.edge_layout.arrays(pred_id)
+        if arrays is None:
+            scanned = 0
+            out = empty_table(cols[:1] if cols[0] == cols[1] else cols,
+                              cap or _pow2(0))
+        else:
+            scanned = int(arrays[0].shape[0])
+            out = _edge_pairs(*arrays, pred_id, pass_src, pass_dst, cols,
+                              cap)
         if sp.live:
-            sp.set(rows=out.count, cap=out.cap)
+            sp.set(rows=out.count, cap=out.cap, scanned=scanned)
+    if telemetry is not None:
+        telemetry.edge_scan_rows += scanned
     return out
 
 
-def _edge_pairs(graph, pred_id, pass_src, pass_dst, cols, cap) -> Table:
-    src = jnp.asarray(graph.src)
-    dst = jnp.asarray(graph.dst)
-    pred = jnp.asarray(graph.pred)
+def _edge_pairs(src, dst, pred, pred_id, pass_src, pass_dst, cols,
+                cap) -> Table:
+    e = src.shape[0]
     p = jnp.int32(-1 if pred_id is None else pred_id)
     mask = _edge_pairs_mask(src, dst, pred, p, pass_src, pass_dst,
                             src_iv=isinstance(pass_src, tuple),
@@ -248,9 +260,8 @@ def _edge_pairs(graph, pred_id, pass_src, pass_dst, cols, cap) -> Table:
         cap2 = cap or _pow2(count)
         if count > cap2:
             raise CapacityOverflow(count)
-        idx = jnp.nonzero(mask, size=cap2, fill_value=graph.num_edges)[0]
-        s = jnp.where(idx < graph.num_edges,
-                      src[jnp.minimum(idx, graph.num_edges - 1)], -1)
+        idx = jnp.nonzero(mask, size=cap2, fill_value=e)[0]
+        s = jnp.where(idx < e, src[jnp.minimum(idx, e - 1)], -1)
         return Table(cols=(cols[0],), rows=s[:, None].astype(jnp.int32),
                      count=count)
     count = int(host_read(mask.sum(), "edge_count"))
@@ -908,11 +919,13 @@ def dtree_candidates(graph: RDFGraph, tree: DTree,
             if outgoing:
                 pairs = edge_pairs(graph, pred, pass_masks[tree.root],
                                    pass_masks[child],
-                                   cols=(tree.root, child), tracer=tracer)
+                                   cols=(tree.root, child), tracer=tracer,
+                                   telemetry=telemetry)
             else:
                 pairs = edge_pairs(graph, pred, pass_masks[child],
                                    pass_masks[tree.root],
-                                   cols=(child, tree.root), tracer=tracer)
+                                   cols=(child, tree.root), tracer=tracer,
+                                   telemetry=telemetry)
             if table is None:
                 table = pairs
             else:

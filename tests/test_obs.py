@@ -303,6 +303,23 @@ def test_host_syncs_alike_traced_or_not_and_one_sync_span_each(graph, pool):
         assert all(s.attrs["what"] for s in syncs)
 
 
+def test_edge_scan_rows_alike_traced_or_not_and_sum_of_scan_spans(graph,
+                                                                  pool):
+    """`QueryStats.edge_scan_rows` is exactly the `scanned` attributes of
+    the request's `edge_pairs` spans summed, traced or not."""
+    traced = QueryServer(graph, cfg=_forcing_cfg(), tracer=Tracer())
+    plain = QueryServer(graph, cfg=_forcing_cfg())
+    for q in pool * 2:                   # cold, then warm replay
+        f = traced.submit(q)
+        got = f.result().stats.edge_scan_rows
+        assert got == plain.query(q).stats.edge_scan_rows > 0
+        scans = [s for s in traced.tracer.get(f.trace_id).spans
+                 if s.name == "edge_pairs"]
+        assert scans and got == sum(s.attrs["scanned"] for s in scans)
+        # every predicate of this graph has a group under E rows
+        assert all(0 < s.attrs["scanned"] < graph.num_edges for s in scans)
+
+
 def test_untraced_server_opens_no_profiler_annotation(graph, pool,
                                                       monkeypatch):
     import jax.profiler

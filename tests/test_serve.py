@@ -495,6 +495,7 @@ def test_query_stats_to_dict_schema_pinned():
         "join_est_rows", "join_actual_rows",
         "join_est_log_err", "join_est_log_bias",
         "plan_mode", "sorts_performed", "sorts_avoided",
+        "edge_scan_rows",
         "plan_cost", "greedy_plan_cost",
         "conn_reach_pairs", "conn_connected_pairs",
         "conn_endpoint_rows", "conn_endpoint_distinct",
