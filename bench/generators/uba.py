@@ -21,6 +21,9 @@ university, and every telephone number is "xxx-xxx-xxxx".
 URIs are "Type/<zero-padded id>" with ids counted over the whole graph,
 so a type's instances are one contiguous range of sorted labels; the
 harness permutes each type's ids per `--seed`.
+
+A configuration whose `generator` is "uba" is generated here
+(`bench.traffic.make_data` finds this file by that name).
 """
 from __future__ import annotations
 
@@ -32,8 +35,8 @@ PROFESSORS = RANKS[:3]          # the ranks that advise students
 TELEPHONE = "xxx-xxx-xxxx"
 
 
-def uba(config: dict) -> tuple[list[tuple[str, str, str]], set[str],
-                               dict[str, int]]:
+def generate(config: dict) -> tuple[list[tuple[str, str, str]], set[str],
+                                    dict[str, int]]:
     """(triples, literal objects, instances per type) of LUBM(N), N the
     configuration's `universities`, drawn from its `data_seed`."""
     prof = config["profile"]
@@ -164,7 +167,3 @@ def uba(config: dict) -> tuple[list[tuple[str, str, str]], set[str],
                         prof["publications_per_graduate"])):
                     add((pub, "publicationAuthor", g))
     return triples, literals, counts
-
-
-# by the `generator` key of a configuration file
-GENERATORS = {"uba": uba}

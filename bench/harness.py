@@ -26,8 +26,9 @@ from .drive import closed_loop, open_loop
 from .graph import Graph, relabel
 from .profile_reduce import read_xplane, reduce_trace
 from .reference import Reference
-from .traffic import (BENCH, Cell, make_data, poisson_dues, rng_for,
-                      shuffled_cycles, template_stream, zipf_sequence)
+from .traffic import (BENCH, Cell, check_generated, make_data,
+                      poisson_dues, rng_for, shuffled_cycles,
+                      template_stream, zipf_sequence)
 
 # past the window's close, requests due in it are still served this long
 DRAIN_S = 60.0
@@ -96,8 +97,9 @@ class Prepared:
     queries: list                    # the program's form of each
     server: object
     to_ref: np.ndarray               # program node id -> reference id
-    excluded_s: float                # the benchmark's own graph, sampling
-                                     # and reference: not set-up
+    excluded_s: float                # the benchmark's own data check,
+                                     # graph, sampling and reference:
+                                     # not set-up
 
 
 def prepare(cell: Cell, seed: int, trace: bool, system) -> Prepared:
@@ -107,13 +109,14 @@ def prepare(cell: Cell, seed: int, trace: bool, system) -> Prepared:
     guarantees = cell.config["guarantees"]
 
     t = clock()
-    triples, literals, counts = make_data(cell.config)
+    triples, literals, counts = make_data(cell.config, cell.bench_dir)
     served, back = relabel(triples, counts, rng_for(seed, "relabel"))
     forward = {v: k for k, v in back.items()}
     dataset = system.load(served, literals)
     log(f"data: {cell.config['name']} ({cell.config['generator']}) "
         f"triples={len(served)} load_s={clock() - t}")
     t = clock()
+    check_generated(triples, literals, counts)
     graph = Graph(triples, literals)
     ref = Reference(graph, max_rows=int(guarantees["max_answer_rows"]),
                     max_intermediate=int(guarantees["row_guard"]))
@@ -126,8 +129,8 @@ def prepare(cell: Cell, seed: int, trace: bool, system) -> Prepared:
     pool = list(islice(template_stream(graph, ref, recipe, forward),
                        int(recipe["pool"])))
     excluded_s = clock() - t
-    log(f"reference: {excluded_s} s for the benchmark's graph, template "
-        f"sampling and reference answers")
+    log(f"reference: {excluded_s} s for the benchmark's data check, "
+        f"graph, template sampling and reference answers")
     queries = [system.query(dataset, e.served) for e in pool]
     server = system.server(dataset, trace, **cell.traffic.get("server", {}))
     # every pass but the last submits the pool as one batch; the last runs
@@ -270,7 +273,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, system,
                                   "unit": m["unit"]}
     else:
         files = glob.glob(f"{profile_dir}/**/*.xplane.pb", recursive=True)
-        layers = json.loads((BENCH / "layers.json").read_text())["layers"]
+        layers = json.loads(
+            (cell.bench_dir / "layers.json").read_text())["layers"]
         planes = read_xplane(files[0])
         shutil.rmtree(profile_dir, ignore_errors=True)
         log("trace planes: " + "; ".join(
@@ -294,7 +298,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, system,
         breakdown = {"device_ops": [[k, v] for k, v in top],
                      "idle_gaps": [[k, v] for k, v in tr["gaps"][:10]]}
         for m in cell.per_layer:
-            v = load_metric(m["name"])(window)
+            v = load_metric(m["name"], cell.bench_dir)(window)
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     line["metrics"] = metrics

@@ -1,12 +1,16 @@
 """The benchmark's LUBM generator keeps UBA's profile: every count inside
-its published range, and everything but degrees inside one department."""
+its published range, and everything but degrees inside one department;
+its data at the cell's size and at a small one is pinned by hash; and
+it keeps the instance-label convention that `check_generated` asserts."""
+import hashlib
 import json
 from collections import Counter, defaultdict
 
 import pytest
 
-from bench.rdf_gen import RANKS, uba
-from bench.traffic import BENCH
+from bench.generators.uba import RANKS
+from bench.generators.uba import generate as uba
+from bench.traffic import BENCH, check_generated
 
 
 def _config(data_seed):
@@ -98,3 +102,65 @@ def test_bench_uba_is_department_local():
 def test_bench_uba_same_seed_same_data():
     assert uba(_config(5)) == uba(_config(5))
     assert uba(_config(5))[0] != uba(_config(6))[0]
+
+
+def _lubm(small):
+    config = json.loads((BENCH / "configs" / "lubm.json").read_text())
+    if small:
+        config["universities"] = 1
+        config["profile"]["departments_per_university"] = [2, 2]
+    return config
+
+
+@pytest.mark.parametrize("small,n_triples,digest", [
+    (True, 15_578, "e64f1be0cd689ed1"),
+    (False, 522_428, "f7f580a3124b649b"),
+], ids=["small", "lubm.json"])
+def test_bench_uba_golden(small, n_triples, digest):
+    """The data the cell serves, byte for byte: sha256 over the sorted
+    triples, tab-separated, one a line."""
+    triples, _, _ = uba(_lubm(small))
+    assert len(triples) == n_triples
+    text = "\n".join("\t".join(t) for t in sorted(triples))
+    assert hashlib.sha256(text.encode()).hexdigest().startswith(digest)
+
+
+def test_bench_check_generated_passes_uba():
+    check_generated(*uba(_lubm(True)))
+
+
+def _paper_graph():
+    triples = [("Paper/00000000", "cites", "Paper/00000001"),
+               ("Paper/00000001", "title", "Title1")]
+    return triples, {"Title1"}, {"Paper": 2}
+
+
+def _break(how):
+    triples, literals, counts = _paper_graph()
+    if how == "unpadded":
+        triples[0] = ("Paper/7", "cites", "Paper/00000001")
+    elif how == "count_disagrees":
+        counts["Paper"] = 3
+    elif how == "uncounted_type":
+        triples.append(("Author/00000000", "wrote", "Paper/00000000"))
+    elif how == "not_strings":
+        triples.append(("Paper/00000000", "year", 2009))
+    elif how == "literal_not_an_object":
+        literals.add("Title2")
+    return triples, literals, counts
+
+
+def test_bench_check_generated_accepts_the_convention():
+    check_generated(*_break("none"))
+
+
+@pytest.mark.parametrize("how,says", [
+    ("unpadded", "instance counts say 2, the labels hold 1 ids"),
+    ("count_disagrees", "instance counts say 3, the labels hold 2 ids"),
+    ("uncounted_type", "types not in the instance counts: ..Author.."),
+    ("not_strings", "is not three strings"),
+    ("literal_not_an_object", "literal objects are no triple's object"),
+])
+def test_bench_check_generated_refuses(how, says):
+    with pytest.raises(ValueError, match=says):
+        check_generated(*_break(how))

@@ -4,8 +4,17 @@ A cell of `BENCHMARK.json` names a configuration (`configs[].file`, a
 JSON file of the deployment: generator, size, profile, the generator's
 own seed, the guarantees) and a traffic mix
 (`bench/traffic/<traffic>.json`: the template recipe, popularity and
-arrivals).  Adding either is adding a
-file and an entry; no code here names one.
+arrivals).  A configuration is its file plus an optional generator
+file: its `generator` key names `bench/generators/<generator>.py`,
+which it brings as its own or shares with another configuration, and
+whose `generate(config) -> (triples, literal objects, instances per
+type)` makes the data.  Adding any of these is adding a file and an
+entry; no code here names one.
+
+A generator's data keeps one convention, which `check_generated`
+asserts before a run uses it: every instance of a type counted in
+"instances per type" is labelled "Type/<8-digit id>", with the ids
+0..count-1, and only those types' instances have such labels.
 
 Steadiness.  Every seed gets the same work in another order:
 
@@ -28,8 +37,10 @@ engine can build for it passes the deployment's row guard
 """
 from __future__ import annotations
 
+import importlib.util
 import itertools
 import json
+import re
 from dataclasses import dataclass
 from pathlib import Path
 
@@ -37,13 +48,14 @@ import numpy as np
 
 from .graph import Graph
 from .queries import Template, random_query
-from .rdf_gen import GENERATORS
 from .reference import Reference
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
 # candidates per template slot; under 10^3, the seed stride between slots
 MAX_ATTEMPTS = 200
+# an instance's label: its type, a slash and its zero-padded id
+INSTANCE = re.compile(r"([A-Za-z]\w*)/(\d{8})")
 
 
 def rng_for(seed: int, stream: str) -> np.random.Generator:
@@ -61,6 +73,8 @@ class Cell:
     # per-layer metric entries of BENCHMARK.json this cell reports
     per_layer: list
     end_to_end: list
+    # the directory that holds its generators and metric readers
+    bench_dir: Path
 
 
 def load_cell(workload: str, root: Path = ROOT) -> Cell:
@@ -80,13 +94,62 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     return Cell(name=workload, chips=int(w["chips"]), config=config,
                 traffic=traffic,
                 per_layer=[m for m in bench["per_layer"] if mine(m)],
-                end_to_end=[m for m in bench["end_to_end"] if mine(m)])
+                end_to_end=[m for m in bench["end_to_end"] if mine(m)],
+                bench_dir=root / "bench")
 
 
-def make_data(config: dict) -> tuple[list, set, dict]:
+def make_data(config: dict, bench_dir: Path = BENCH
+              ) -> tuple[list, set, dict]:
     """(triples, literal objects, instances per type) of a configuration:
-    its generator over the whole configuration (size, profile, seed)."""
-    return GENERATORS[config["generator"]](config)
+    the `generate` of `<bench_dir>/generators/<generator>.py` over the
+    whole configuration (size, profile, seed)."""
+    name = config["generator"]
+    path = bench_dir / "generators" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {config['name']!r} names "
+                                f"the generator {name!r}, but {path} does "
+                                f"not exist")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_generator_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.generate(config)
+
+
+def check_generated(triples, literals, counts: dict[str, int]) -> None:
+    """Raise ValueError where a generator's data breaks the convention
+    that `graph.relabel` needs to permute it: every triple three
+    strings; each type of `counts` labelled "Type/<8-digit id>" with
+    exactly the ids 0..count-1; no such label of a type `counts` does
+    not name; the literal objects among the triples' objects.  A label
+    `relabel` does not know stays as it is, so a break would serve
+    every `--seed` the same graph, with no error."""
+    bad = next((t for t in triples
+                if len(t) != 3 or not all(isinstance(x, str) for x in t)),
+               None)
+    if bad is not None:
+        raise ValueError(f"triple {bad!r} is not three strings")
+    objects = {o for _, _, o in triples}
+    ids: dict[str, set] = {}
+    for label in objects.union(s for s, _, _ in triples):
+        m = INSTANCE.fullmatch(label)
+        if m:
+            ids.setdefault(m[1], set()).add(int(m[2]))
+    stray = sorted(set(ids) - set(counts))
+    if stray:
+        raise ValueError(f"labels of the form Type/<8-digit id> for types "
+                         f"not in the instance counts: {stray}")
+    for kind, count in counts.items():
+        got, want = ids.get(kind, set()), set(range(count))
+        if got != want:
+            raise ValueError(
+                f"{kind}: the instance counts say {count}, the labels hold "
+                f"{len(got)} ids of {kind}/<8 digits>, {len(got - want)} "
+                f"of them outside 0..{count - 1}")
+    missing = set(literals) - objects
+    if missing:
+        raise ValueError(f"{len(missing)} literal objects are no triple's "
+                         f"object, such as {sorted(missing)[:3]}")
 
 
 # -------------------------------------------------------------------- #
