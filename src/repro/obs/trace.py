@@ -36,6 +36,17 @@ device value to the host.  It counts every call in a process counter
 execution) and, while a live tracer has a segment open, wraps the copy
 in a ``sync`` span: the host waiting on the device, plus the copy.
 
+Read tapes: a warm replay of a fixed template over an immutable dataset
+makes the same 0-d count reads, with the same answers, every time.  While
+a `ReadTape` is open (`with tape:`) a recording tape appends every 0-d
+read's ``(what, value)``, and a replaying tape answers each 0-d read from
+its entries without touching the device: no sync, no ``sync`` span, not
+counted.  It keeps the device scalar it was handed instead, and
+`final_read` fetches all of them beside the execution's last read, so
+the tape is checked exactly, on the device's own answers, at no extra
+round trip.  Reads of data (rows, column values, count vectors) are
+never taped.
+
 Stdlib-only at import: ``repro.core`` imports this module, so JAX is
 imported lazily, inside the live path and inside `host_read`.
 """
@@ -45,10 +56,13 @@ import itertools
 import json
 import time
 from collections import deque
+from contextvars import ContextVar
 
 _profiler = None                # jax.profiler, bound by the first live span
 _live: "Tracer | None" = None   # the tracer with an open segment, if any
 _host_syncs = 0                 # host_read calls in this process
+# the open read tape, if any: per thread, since it decides answers
+_tape: ContextVar["ReadTape | None"] = ContextVar("read_tape", default=None)
 
 
 def _scalars(attrs: dict) -> dict:
@@ -71,7 +85,23 @@ def _open_annotation(name: str, attrs: dict):
 def host_read(x, what: str):
     """`jax.device_get(x)`, counted, and timed as a ``sync`` span (attr
     ``what``) under the live tracer's open segment.  With tracing off it
-    adds one integer increment to the read."""
+    adds one integer increment to the read.  Under an open `ReadTape` a
+    0-d read is recorded, or answered from the tape."""
+    tape = _tape.get()
+    if tape is not None and getattr(x, "shape", None) == ():
+        if tape.replaying:
+            hit = tape.answer(x, what)
+            if hit is not None:
+                return hit[1]
+        elif tape.recording:
+            value = _read(x, what)
+            tape.entries.append((what, value))
+            return value
+    return _read(x, what)
+
+
+def _read(x, what: str):
+    """`host_read` past the tape: a real read, counted and spanned."""
     global _host_syncs
     _host_syncs += 1
     tracer = _live
@@ -79,6 +109,17 @@ def host_read(x, what: str):
         return _device_get(x)
     with tracer.span("sync", what=what):
         return _device_get(x)
+
+
+def final_read(x, what: str):
+    """`host_read(x, what)` for an execution's last read.  Under a
+    replaying tape the same read fetches every device scalar the tape
+    answered for, and `ReadTape.failed` says whether any differs from
+    the tape's answer."""
+    tape = _tape.get()
+    if tape is None or not tape.kept:
+        return _read(x, what)
+    return tape.check(x, what)
 
 
 def _device_get(x):
@@ -89,6 +130,59 @@ def _device_get(x):
 def host_syncs() -> int:
     """`host_read` calls so far in this process."""
     return _host_syncs
+
+
+class ReadTape:
+    """The 0-d reads of one warm execution, as ``(what, value)`` entries.
+
+    ``ReadTape()`` records: inside ``with tape:`` every 0-d `host_read` is
+    read for real and appended to `entries`.  ``ReadTape(entries)``
+    replays: a 0-d read whose ``what`` is the next entry's returns that
+    entry's value, and its device scalar is kept for `check`.  The first
+    read that differs in ``what``, or one past the end, stops the replay
+    for the rest of the execution (`diverged`), and reads are real
+    again.  After `check`, `failed` says whether the device disagreed
+    with any answer the tape gave; an execution that read a wrong answer
+    is to be discarded."""
+    __slots__ = ("entries", "recording", "replaying", "kept", "diverged",
+                 "failed", "_token")
+
+    def __init__(self, entries: list | None = None):
+        self.recording = entries is None
+        self.replaying = not self.recording
+        self.entries: list = [] if entries is None else entries
+        self.kept: list = []            # device scalars of answered reads
+        self.diverged = False
+        self.failed = False
+
+    def answer(self, x, what: str):
+        """The next entry, where it answers this read; else None, and the
+        replay stops."""
+        i = len(self.kept)
+        if i < len(self.entries) and self.entries[i][0] == what:
+            self.kept.append(x)
+            return self.entries[i]
+        self.replaying = False
+        self.diverged = True
+        return None
+
+    def check(self, x=(), what: str = "tape_check"):
+        """Read `x` and every kept device scalar in one `host_read`, and
+        set `failed` where a scalar differs from the tape's answer.
+        Returns `x` as read."""
+        got = _read((x, *self.kept), what)
+        self.failed = any(bool(v != e[1])
+                          for v, e in zip(got[1:], self.entries))
+        return got[0]
+
+    def __enter__(self) -> "ReadTape":
+        self._token = _tape.set(self)
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        _tape.reset(self._token)
+        self.replaying = self.recording = False
+        return False
 
 
 class _NullSpan:

@@ -431,7 +431,7 @@ class QueryServer:
                                       res.cols, res.rows,
                                       bool(pq.query.connections), pq.iv)
             seg.set(outcome="ok", warm=bool(res.stats.cache_hit),
-                    rows=res.count)
+                    rows=res.count, replayed=res.stats.replayed_reads)
         return res, lat
 
     def _execute_governed(self, pq) -> MatchResult:

@@ -290,10 +290,11 @@ def test_traced_server_spans_land_on_the_profiler_clock(graph, pool,
 
 def test_host_syncs_alike_traced_or_not_and_one_sync_span_each(graph, pool):
     """`QueryStats.host_syncs` does not depend on tracing, and a traced
-    execution holds one `sync` span per counted read."""
+    execution holds one `sync` span per counted read, also where a
+    replay answers reads from its tape."""
     traced = QueryServer(graph, cfg=_forcing_cfg(), tracer=Tracer())
     plain = QueryServer(graph, cfg=_forcing_cfg())
-    for q in pool * 2:                   # cold, then warm replay
+    for q in pool * 3:                   # cold, recording, then replaying
         f = traced.submit(q)
         got = f.result().stats.host_syncs
         assert got == plain.query(q).stats.host_syncs > 0
